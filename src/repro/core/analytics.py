@@ -18,6 +18,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from . import device_cache
+from ..obs.trace import TRACER as _trc
 
 
 # ---------------------------------------------------------------------------
@@ -165,11 +166,12 @@ def _plane(view, device: Optional[bool]):
 def pagerank_view(
     view, iters: int = 10, damping: float = 0.85, device: Optional[bool] = None
 ) -> jnp.ndarray:
-    plane = _plane(view, device)
-    if plane is not None:
-        return plane.pagerank(view, iters=iters, damping=damping)
-    src, dst = _view_coo(view, device)
-    return pagerank_coo(src, dst, view.n_vertices, iters=iters, damping=damping)
+    with _trc.site("pagerank"):
+        plane = _plane(view, device)
+        if plane is not None:
+            return plane.pagerank(view, iters=iters, damping=damping)
+        src, dst = _view_coo(view, device)
+        return pagerank_coo(src, dst, view.n_vertices, iters=iters, damping=damping)
 
 
 def bfs_view(view, root: int, device: Optional[bool] = None) -> jnp.ndarray:

@@ -163,17 +163,24 @@ class Compactor:
         tok = _trc.begin()
         wp = store.write_pipeline
         if wp is not None:
+            # the quiesce span is the whole time submitters are held off;
+            # drain_s of it is the flush, the rest the fold
             with wp.quiesce():
+                drained = _trc.begin()
                 report = self._fold(locked=True)
                 wp.invalidate_heads(report.repacked)
+            if tok and drained:
+                _trc.end(tok, "quiesce", cat="compact", ts=report.horizon,
+                         args={"drain_s": (drained - tok) / 1e9})
         else:
             report = self._fold(locked=False)
-        _trc.end(tok, "compactor_fold", cat="compact", ts=report.horizon, args={
-            "versions_reclaimed": report.versions_reclaimed,
-            "repacked": len(report.repacked),
-            "rows_freed": report.rows_freed,
-            "lineage_trimmed": report.lineage_trimmed,
-        })
+        if tok:
+            _trc.end(tok, "compactor_fold", cat="compact", ts=report.horizon, args={
+                "versions_reclaimed": report.versions_reclaimed,
+                "repacked": len(report.repacked),
+                "rows_freed": report.rows_freed,
+                "lineage_trimmed": report.lineage_trimmed,
+            })
         if checkpoint and self.checkpoint_dir is not None:
             from ..checkpoint import manager as _ckpt
 

@@ -148,7 +148,6 @@ def _device_put(host_arrays: Sequence[np.ndarray], wait: bool = True,
     count the transfer."""
     import jax
 
-    tok = _trc.begin()
     out = tuple(jax.device_put(a, device) for a in host_arrays)
     if wait:
         for o in out:
@@ -156,13 +155,7 @@ def _device_put(host_arrays: Sequence[np.ndarray], wait: bool = True,
     stats.add("uploads", len(host_arrays))
     # charge the *device* bytes: device_put canonicalizes int64 -> int32
     # under default x64-disabled JAX, halving the resident size
-    nbytes = int(sum(o.nbytes for o in out))
-    stats.add("bytes_uploaded", nbytes)
-    if tok:
-        args = {"nbytes": nbytes, "n_arrays": len(host_arrays)}
-        if device is not None:
-            args["device"] = int(device.id)
-        _trc.end(tok, "upload", cat="read", args=args)
+    stats.add("bytes_uploaded", int(sum(o.nbytes for o in out)))
     return out
 
 
@@ -222,17 +215,19 @@ def _pad_tiles_on_device(data, lens, B: int):
 
     tok = _trc.begin()
     n = int(lens.shape[0])
-    if int(data.shape[0]) == 0:
-        # no live values (possibly no tiles at all): pure-SENTINEL tiles.
-        # Zero-element results fall off their committed device (jax places
-        # any 0-sized output on the default device), and this tuple is
-        # cached per-(snapshot, device) — re-commit explicitly.
-        out = jax.device_put(
-            jnp.broadcast_to(lens[:, None] * 0 + jnp.int32(SENTINEL), (n, B)),
-            next(iter(lens.devices())),
-        )
-    else:
-        out = _pad_tiles_program()(data, lens, B=B)
+    with _trc.site("tier_repad"):
+        if int(data.shape[0]) == 0:
+            # no live values (possibly no tiles at all): pure-SENTINEL
+            # tiles.  Zero-element results fall off their committed device
+            # (jax places any 0-sized output on the default device), and
+            # this tuple is cached per-(snapshot, device) — re-commit
+            # explicitly.
+            out = jax.device_put(
+                jnp.broadcast_to(lens[:, None] * 0 + jnp.int32(SENTINEL), (n, B)),
+                next(iter(lens.devices())),
+            )
+        else:
+            out = _pad_tiles_program()(data, lens, B=B)
     if tok:
         _trc.end(tok, "tier_repad", cat="read", args={"n_tiles": n, "B": B})
     return out
@@ -389,7 +384,6 @@ def _shard_cache_put(snap, key, host_arrays, device, wait, finish=None):
     """
     import jax
 
-    tok = _trc.begin()
     up = tuple(jax.device_put(a, device) for a in host_arrays)
     if wait:
         for t in up:
@@ -397,10 +391,6 @@ def _shard_cache_put(snap, key, host_arrays, device, wait, finish=None):
     nbytes = int(sum(int(t.nbytes) for t in up))
     stats.add("uploads", len(host_arrays))
     stats.add("bytes_uploaded", nbytes)
-    if tok:
-        _trc.end(tok, "upload", cat="read",
-                 args={"nbytes": nbytes, "n_arrays": len(host_arrays),
-                       "device": int(device.id)})
     tiles = up if finish is None else finish(up)
     if snap._shard_dev_cache is None:
         snap._shard_dev_cache = {}
@@ -482,7 +472,6 @@ def stage_shard_tiles(snap, device, kind: str, wait: bool = False):
     cache = snap._shard_dev_cache
     if cache is not None and key in cache:
         return key, cache[key], 0
-    tok = _trc.begin()
     if kind == "coo":
         host = snap.to_coo_global()
         up = tuple(jax.device_put(a, device) for a in host)
@@ -497,10 +486,6 @@ def stage_shard_tiles(snap, device, kind: str, wait: bool = False):
     nbytes = int(sum(int(t.nbytes) for t in up))
     stats.add("uploads", len(up))
     stats.add("bytes_uploaded", nbytes)
-    if tok:
-        _trc.end(tok, "upload", cat="read",
-                 args={"nbytes": nbytes, "n_arrays": len(up),
-                       "device": int(device.id)})
     return key, tiles, nbytes
 
 

@@ -21,8 +21,7 @@ operators scrape:
 - ``pipeline_queue_depth{shard=k}``: write-pipeline backlog (a hot writer
   shard is also a hot reader shard under the store's workloads);
 - ``shard_plane_uploads{shard=k}`` / ``ShardPlaneStats`` per-shard upload
-  and compute counters, plus ``kernel_dispatch`` span rates when tracing is
-  live, for diagnostics in the plan's ``reason``.
+  and compute counters, for diagnostics in the plan's ``reason``.
 
 Migration-epoch lifecycle
 -------------------------

@@ -125,7 +125,6 @@ from typing import Callable, Dict, List, Optional, Sequence, Union
 
 import numpy as np
 
-from ..obs.trace import TRACER as _trc
 from .hooks import RESHARD_HOOKS
 from .leaf_pool import SENTINEL
 
@@ -1054,20 +1053,6 @@ class ShardPlane:
         with self._lock:
             self.stats.collective_calls += 1
 
-    def _dispatch(self, kernel: str, fn: Callable, *args):
-        """Invoke a jitted collective under a ``kernel_dispatch`` span.
-
-        The span covers trace/compile on the first call and pure device
-        execution afterwards; the ``kernel`` arg names the collective so
-        the Perfetto timeline separates compile spikes per kernel.
-        """
-        tok = _trc.begin()
-        out = fn(*args)
-        if tok:
-            _trc.end(tok, "kernel_dispatch", cat="read",
-                     args={"kernel": kernel, "n_shards": len(self.devices)})
-        return out
-
     def pagerank(self, view, iters: int = 10, damping: float = 0.85):
         """Collective PageRank over pinned shard tiles (module docstring
         covers the pull-vs-push choice and the bitwise contract)."""
@@ -1087,9 +1072,7 @@ class ShardPlane:
                 )
             ),
         )
-        return self._dispatch(
-            "pagerank", fn, *coo.global_arrays(self.mesh, self.axis)
-        )
+        return fn(*coo.global_arrays(self.mesh, self.axis))
 
     def bfs(self, view, root: int):
         """Collective level-synchronous BFS (bitwise-equal to ``bfs_view``)."""
@@ -1105,9 +1088,7 @@ class ShardPlane:
             ("bfs", n, coo.cap),
             lambda: jax.jit(distributed.make_bfs(self.mesh, self.axis, n)),
         )
-        return self._dispatch(
-            "bfs", fn, *coo.global_arrays(self.mesh, self.axis), jnp.int32(root)
-        )
+        return fn(*coo.global_arrays(self.mesh, self.axis), jnp.int32(root))
 
     def _shard_edge_operand(self, coo: ShardedKind, w: np.ndarray) -> tuple:
         """Slice a per-edge operand (global COO order) onto the shards.
@@ -1161,10 +1142,7 @@ class ShardPlane:
             ("sssp", n, coo.cap),
             lambda: jax.jit(distributed.make_sssp(self.mesh, self.axis, n)),
         )
-        return self._dispatch(
-            "sssp", fn, *coo.global_arrays(self.mesh, self.axis), gw,
-            jnp.int32(root)
-        )
+        return fn(*coo.global_arrays(self.mesh, self.axis), gw, jnp.int32(root))
 
     def wcc(self, view):
         """Collective WCC: both edge directions propagate locally, ``pmin``
@@ -1180,9 +1158,7 @@ class ShardPlane:
             ("wcc", n, coo.cap),
             lambda: jax.jit(distributed.make_wcc(self.mesh, self.axis, n)),
         )
-        return self._dispatch(
-            "wcc", fn, *coo.global_arrays(self.mesh, self.axis)
-        )
+        return fn(*coo.global_arrays(self.mesh, self.axis))
 
     def spmm(self, view, h, n_block: int = 64, v_tile: int = 512):
         """Collective per-vertex SpMM over pinned leaf tiles.
@@ -1223,10 +1199,8 @@ class ShardPlane:
             return jax.jit(sp)
 
         fn = self._fn(("spmm", n, blocks.cap, view.B, n_block, v_tile), build)
-        return self._dispatch(
-            "spmm", fn, *blocks.global_arrays(self.mesh, self.axis),
-            jnp.asarray(h, jnp.float32)
-        )
+        return fn(*blocks.global_arrays(self.mesh, self.axis),
+                  jnp.asarray(h, jnp.float32))
 
 
 __all__ = [

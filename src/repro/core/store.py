@@ -384,6 +384,8 @@ class RapidStore:
         all S.  Weak linkage keeps GC free to reclaim superseded bundles.
         """
         token = _trc.begin()
+        if token:
+            _trc.anchor()  # ties the ring's clock to a running profiler's
         t = self.clock.read_timestamp()
         slot = self.tracer.register(t)
         # Close the register/GC race: re-read t_r after publishing our slot;

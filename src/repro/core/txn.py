@@ -234,19 +234,23 @@ def commit(
             tok = _trc.begin()
             wal.append_commit(t, rw.ins, rw.dels, rw.vset, store.n_vertices)
             wal.sync()
-            _trc.end(tok, "wal_sync", cat="write", ts=t)
+            if tok:
+                _trc.end(tok, "wal_sync", cat="write", ts=t)
         tok = _trc.begin()
         link_at(store, t, new_snaps, n_writes=n_writes)
-        _trc.end(tok, "link", cat="write", ts=t)
+        if tok:
+            _trc.end(tok, "link", cat="write", ts=t)
     except BaseException:
         if ts is None:  # we drew it; a reserving caller owns its own range
             store.clock.abandon(t)
         raise
     tok = _trc.begin()
     store.clock.publish(t)
-    _trc.end(tok, "publish", cat="write", ts=t)
+    if tok:
+        _trc.end(tok, "publish", cat="write", ts=t)
     store.stats.add("commits", 1)
-    _trc.end(tok_commit, "commit", cat="write", ts=t)
+    if tok_commit:
+        _trc.end(tok_commit, "commit", cat="write", ts=t)
     return t
 
 
@@ -275,7 +279,8 @@ def execute_write(
     """
     tok = _trc.begin()
     rw = route(store, ins, dels, vset)
-    _trc.end(tok, "route", cat="write")
+    if tok:
+        _trc.end(tok, "route", cat="write")
     if rw is None:
         return 0
 
@@ -285,13 +290,15 @@ def execute_write(
     try:
         tok = _trc.begin()
         new_snaps = prepare(store, rw)
-        _trc.end(tok, "prepare", cat="write", args={"n_writes": 1})
+        if tok:
+            _trc.end(tok, "prepare", cat="write", args={"n_writes": 1})
         if not new_snaps:
             return 0
         t = commit(store, new_snaps, rw=rw)
         tok = _trc.begin()
         reclaim(store, new_snaps)
-        _trc.end(tok, "reclaim", cat="write", ts=t)
+        if tok:
+            _trc.end(tok, "reclaim", cat="write", ts=t)
         return t
     finally:
         for sid in reversed(rw.sids):

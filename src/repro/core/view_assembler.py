@@ -759,7 +759,7 @@ def _splice_device(pred_cols, pred_offsets, segs, S):
     Equal-sized dirty segments are patched with
     ``jax.lax.dynamic_update_slice`` directly on the predecessor columns;
     any resize falls back to an O(d)-run ``jnp.concatenate``.  Returns
-    ``(columns, offsets)``.
+    ``(columns, offsets, patched)``, ``patched`` true on the first path.
     """
     import jax
     import jax.numpy as jnp
@@ -778,9 +778,34 @@ def _splice_device(pred_cols, pred_offsets, segs, S):
                 start = (int(pred_offsets[sid]),) + (0,) * (seg.ndim - 1)
                 base = jax.lax.dynamic_update_slice(base, seg, start)
             outs.append(base)
-        return tuple(outs), _segment_offsets(counts)
+        return tuple(outs), _segment_offsets(counts), True
     out = _splice_runs(pred_cols, pred_offsets, segs, S, jnp.concatenate)
-    return out, _segment_offsets(counts)
+    return out, _segment_offsets(counts), False
+
+
+def _end_splice(tok, view, path: str, n_dirty: int, cols, segs=None) -> None:
+    """Close the ``view_splice`` span of one view's device assembly.
+
+    ``path`` is ``patch`` (equal-sized segments updated in place),
+    ``concat`` (an O(d)-run concatenate) or ``full`` (every subgraph, as on
+    the first view or above :func:`max_dirty_frac`).  ``n_patches`` counts
+    the dirty segments written over all columns; ``bytes_written`` is what
+    the device writes, from shapes alone: each eager
+    ``dynamic_update_slice`` writes a whole new column, the other paths
+    each output column once.
+    """
+    per_col = [0] * len(cols)
+    for seg in (segs or {}).values():
+        for i, c in enumerate(seg):
+            per_col[i] += bool(c.shape[0])
+    if path == "patch":
+        written = sum(n * int(c.nbytes) for n, c in zip(per_col, cols))
+    else:
+        written = sum(int(c.nbytes) for c in cols)
+    _trc.end(tok, "view_splice", cat="read", ts=view.ts, args={
+        "n_dirty": n_dirty, "n_subgraphs": len(view.snaps), "path": path,
+        "n_patches": sum(per_col), "bytes_written": written,
+    })
 
 
 def _device_blocks_tiered(view, a):
@@ -853,11 +878,16 @@ def device_blocks(view):
             a.dev_blocks = pred.dev_blocks
             _count(reuses=1)
             return a.dev_blocks
-        segs = _device_segs(view, dirty, device_cache.leaf_block_tiles)
-        pb = pred.dev_blocks
-        cols, offsets = _splice_device(
-            (pb.src, pb.rows, pb.length), pred.block_offsets, segs, a.S
-        )
+        tok = _trc.begin()
+        with _trc.site("view_splice"):
+            segs = _device_segs(view, dirty, device_cache.leaf_block_tiles)
+            pb = pred.dev_blocks
+            cols, offsets, patched = _splice_device(
+                (pb.src, pb.rows, pb.length), pred.block_offsets, segs, a.S
+            )
+        if tok:
+            _end_splice(tok, view, "patch" if patched else "concat",
+                        len(dirty), cols, segs)
         a.block_offsets = offsets
         a.dev_blocks = device_cache.DeviceLeafBlockView(*cols)
         _count(splices=1, spliced_segments=len(dirty))
@@ -866,11 +896,15 @@ def device_blocks(view):
     # it can be) crosses the bus in one upload and is re-padded on device by
     # one program.  Per-snapshot uploads would compile one program per
     # distinct segment shape — thousands on a real graph.
-    stream = host_stream(view)
-    data, lens, keys = device_cache._device_put(
-        (stream.data, stream.leaf_lens, stream.leaf_keys), wait=False
-    )
-    rows = device_cache._pad_tiles_on_device(data, lens, view.B)
+    tok = _trc.begin()
+    with _trc.site("view_splice"):
+        stream = host_stream(view)
+        data, lens, keys = device_cache._device_put(
+            (stream.data, stream.leaf_lens, stream.leaf_keys), wait=False
+        )
+        rows = device_cache._pad_tiles_on_device(data, lens, view.B)
+    if tok:
+        _end_splice(tok, view, "full", a.S, (keys, rows, lens))
     a.dev_blocks = device_cache.DeviceLeafBlockView(keys, rows, lens)
     _count(full_concats=1)
     return a.dev_blocks
@@ -893,14 +927,26 @@ def device_coo(view) -> tuple:
             a.dev_coo = pred.dev_coo
             _count(reuses=1)
             return a.dev_coo
-        segs = _device_segs(view, dirty, device_cache.coo_tiles)
-        cols, offsets = _splice_device(pred.dev_coo, pred.coo_offsets, segs, a.S)
+        tok = _trc.begin()
+        with _trc.site("view_splice"):
+            segs = _device_segs(view, dirty, device_cache.coo_tiles)
+            cols, offsets, patched = _splice_device(
+                pred.dev_coo, pred.coo_offsets, segs, a.S
+            )
+        if tok:
+            _end_splice(tok, view, "patch" if patched else "concat",
+                        len(dirty), cols, segs)
         a.coo_offsets = offsets
         a.dev_coo = cols
         _count(splices=1, spliced_segments=len(dirty))
         return a.dev_coo
     # full build: one upload of the host COO (see device_blocks)
-    a.dev_coo = device_cache._device_put(host_coo(view), wait=False)
+    tok = _trc.begin()
+    with _trc.site("view_splice"):
+        cols = device_cache._device_put(host_coo(view), wait=False)
+    if tok:
+        _end_splice(tok, view, "full", a.S, cols)
+    a.dev_coo = cols
     _count(full_concats=1)
     return a.dev_coo
 

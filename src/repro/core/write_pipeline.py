@@ -283,7 +283,8 @@ class WritePipeline:
                     with q.cond:
                         q.items.append(fence)
                         q.cond.notify()
-        _trc.end(tok, "enqueue", cat="write", args={"seq": ticket.seq})
+        if tok:
+            _trc.end(tok, "enqueue", cat="write", args={"seq": ticket.seq})
         return ticket
 
     def flush(self, timeout: Optional[float] = None) -> None:
@@ -438,11 +439,12 @@ class WritePipeline:
             self._complete(tickets, ts=0)
             return
         new_snaps = _txn.prepare(self.store, net, heads=self._heads)
-        _trc.end(tok, "prepare", cat="write", args={
-            "n_writes": len(writes),
-            "seq_first": tickets[0].seq,
-            "seq_last": tickets[-1].seq,
-        })
+        if tok:
+            _trc.end(tok, "prepare", cat="write", args={
+                "n_writes": len(writes),
+                "seq_first": tickets[0].seq,
+                "seq_last": tickets[-1].seq,
+            })
         if not new_snaps:
             self.stats.add("noop_batches")
             self._complete(tickets, ts=0)
@@ -506,16 +508,19 @@ class WritePipeline:
                         _txn.link_at(store, first + i, pb.new_snaps,
                                      n_writes=pb.n_writes)
                         linked += 1
-                    _trc.end(tok, "link", cat="write", ts=first,
-                             args={"ts_first": first, "ts_last": first + k - 1})
+                    if tok:
+                        _trc.end(tok, "link", cat="write", ts=first, args={
+                            "ts_first": first, "ts_last": first + k - 1,
+                        })
                     if wal is not None:
                         # ONE durability barrier per drained run, mirroring
                         # the single publish_range below
                         tok = _trc.begin()
                         wal.sync()
-                        _trc.end(tok, "wal_sync", cat="write", ts=first, args={
-                            "ts_first": first, "ts_last": first + k - 1,
-                        })
+                        if tok:
+                            _trc.end(tok, "wal_sync", cat="write", ts=first,
+                                     args={"ts_first": first,
+                                           "ts_last": first + k - 1})
                 except BaseException:
                     # Renounce the reserved-but-unlinked suffix so later
                     # committers step over it instead of stalling to
@@ -534,9 +539,10 @@ class WritePipeline:
                     raise
                 tok = _trc.begin()
                 store.clock.publish_range(first, first + k - 1)
-                _trc.end(tok, "publish", cat="write", ts=first, args={
-                    "ts_first": first, "ts_last": first + k - 1,
-                })
+                if tok:
+                    _trc.end(tok, "publish", cat="write", ts=first, args={
+                        "ts_first": first, "ts_last": first + k - 1,
+                    })
                 store.stats.add("commits", k)
                 store.stats.add("group_commits", k)
                 store.stats.add(
@@ -555,7 +561,8 @@ class WritePipeline:
                 tok = _trc.begin()
                 for pb in run:
                     _txn.reclaim(store, pb.new_snaps)
-                _trc.end(tok, "reclaim", cat="write", ts=first)
+                if tok:
+                    _trc.end(tok, "reclaim", cat="write", ts=first)
             except BaseException as exc:  # pragma: no cover - defensive
                 self._abort(exc, [tk for pb in run for tk in pb.tickets])
                 return

@@ -7,6 +7,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ...obs.trace import TRACER as _trc
 from ..runtime import device_cache_enabled, use_interpret
 from .kernel import leaf_search_kernel
 from .ref import leaf_search_ref
@@ -71,18 +72,24 @@ def edge_search_view(view, us, vs, q_block: int = 256) -> np.ndarray:
                 if not m.any():
                     continue
                 pos = np.searchsorted(dev.gidx[t], flat[m])
-                rows_sel = dev.groups[t][1][jnp.asarray(pos, jnp.int32)]
-                found, _ = leaf_search(
-                    rows_sel, jnp.asarray(vs[qidx[m]], jnp.int32), q_block=q_block
-                )
+                with _trc.site("leaf_gather"):
+                    rows_sel = dev.groups[t][1][jnp.asarray(pos, jnp.int32)]
+                with _trc.site("leaf_search"):
+                    found, _ = leaf_search(
+                        rows_sel, jnp.asarray(vs[qidx[m]], jnp.int32),
+                        q_block=q_block,
+                    )
                 np.logical_or.at(out, qidx[m], np.asarray(found))
             return out
-        rows_sel = dev.rows[jnp.asarray(flat, jnp.int32)]
+        with _trc.site("leaf_gather"):
+            rows_sel = dev.rows[jnp.asarray(flat, jnp.int32)]
     else:
         # host fallback reads the compacted stream natively: only the
         # candidate leaves are padded, never the full [n_leaves, B] matrix
         rows_sel = jnp.asarray(view.to_leaf_stream().gather_padded(flat, view.B))
-    found, _ = leaf_search(rows_sel, jnp.asarray(vs[qidx], jnp.int32), q_block=q_block)
+    with _trc.site("leaf_search"):
+        found, _ = leaf_search(rows_sel, jnp.asarray(vs[qidx], jnp.int32),
+                               q_block=q_block)
     np.logical_or.at(out, qidx, np.asarray(found))
     return out
 

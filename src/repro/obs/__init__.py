@@ -17,10 +17,24 @@ three layers:
 2. :mod:`repro.obs.trace` — a fixed-size, lock-striped **span ring
    buffer**.  Spans cover the commit lifecycle (enqueue → route →
    prepare → wal_sync → link → publish → commit → reclaim), the read
-   lifecycle (read → assemble → tier_repad → upload → kernel_dispatch)
-   and compactor fold cycles, and carry the commit/view timestamp in
-   their args — one write is traceable from submission to the first
-   reader view that observes it.
+   lifecycle (read → assemble → view_splice → tier_repad), the
+   compactor (``compactor_fold``, and ``quiesce``: the whole time
+   submitters are held off, its ``drain_s`` arg the flush), and every
+   backend compile (``compile``: its ``site`` arg is the innermost
+   :meth:`~repro.obs.trace.Tracer.site` region open on the compiling
+   thread — ``view_splice``, ``tier_repad``, ``leaf_gather``,
+   ``leaf_search`` or ``pagerank`` — and ``sites`` the whole open
+   stack).  ``view_splice`` is one span per view's device assembly,
+   with its ``path`` (``patch`` / ``concat`` / ``full``), ``n_dirty``,
+   ``n_subgraphs``, ``n_patches`` and ``bytes_written`` (from shapes).
+   Write spans carry the commit/view timestamp in their args — one
+   write is traceable from submission to the first reader view that
+   observes it.  Spans time the host: around an asynchronous device
+   call they time its dispatch, and the device trace gives device time.
+   :mod:`repro.obs.profile` moves the ring onto a ``jax.profiler``
+   trace's clock through the ``repro.clock`` anchors each traced read
+   writes, and :meth:`~repro.obs.trace.Tracer.clear` stamps
+   ``epoch_ns``, the clock's zero for a measured window.
 3. :mod:`repro.obs.export` — Prometheus text exposition
    (:func:`~repro.obs.export.prometheus_text`), Chrome trace-event JSON
    loadable in Perfetto (:func:`~repro.obs.export.chrome_trace` /
@@ -47,21 +61,27 @@ Overhead contract
 Counters that back the legacy stat surfaces are **always live** — they
 cost what the old locked dicts cost (one uncontended lock per
 increment) and tests rely on them unconditionally.  Everything *added*
-by this plane — span recording and latency-histogram observation — is
-**off by default** and gated behind ``REPRO_TELEMETRY=1`` (or
-:func:`repro.obs.trace.enable`); when disabled the hot-path cost is a
-single attribute check (``TRACER.enabled``).  When enabled, a span
-costs two ``perf_counter_ns`` calls, one tuple build and one striped
-ring slot write; the tier-1 bound (asserted by
-``benchmarks/bench_concurrent.py``) is reader p99 with telemetry on
-≤ 1.1x telemetry off.  The span ring is fixed-size
-(``REPRO_TELEMETRY_RING``, default 32768 spans): saturation overwrites
-the oldest spans per stripe and never blocks or allocates unboundedly.
+by this plane — spans, compile records, clock anchors and
+latency-histogram observation — is **off by default** and gated behind
+``REPRO_TELEMETRY=1`` (or :func:`repro.obs.trace.enable`).  Off, an
+instrumentation site costs one attribute check (``TRACER.enabled``): no
+clock read, no ``args`` dict built, no profiler annotation, a shared
+no-op ``site`` context, and a compile listener that returns at once —
+``tests/test_trace_sites.py::test_tracing_off_costs_nothing`` asserts
+it on a write-pipeline, compactor and search run.  On, a span costs two
+``perf_counter_ns`` calls, one tuple build and one striped ring slot
+write; ``benchmarks/bench_concurrent.py`` (a benchmark, not a tier-1
+test) holds reader p99 with telemetry on within 1.1x of off when run.
+No span ever waits on or reads from the device.  The span ring is
+fixed-size (``REPRO_TELEMETRY_RING``, default 32768 spans): saturation
+overwrites the oldest spans per stripe and never blocks or allocates
+unboundedly.
 """
 
 from .metrics import REGISTRY, Counter, Gauge, Histogram, MetricsRegistry
 from .trace import TRACER, SpanRing, Tracer, enable, enabled
 from .export import chrome_trace, prometheus_text, telemetry_report, write_chrome_trace
+from .profile import busiest_span, clock_offsets, host_events, on_profiler_clock
 
 __all__ = [
     "REGISTRY",
@@ -78,4 +98,8 @@ __all__ = [
     "prometheus_text",
     "telemetry_report",
     "write_chrome_trace",
+    "busiest_span",
+    "clock_offsets",
+    "host_events",
+    "on_profiler_clock",
 ]

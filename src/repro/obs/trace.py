@@ -6,9 +6,13 @@ the store's hot paths:
 
 - **Disabled** (the default): the only cost at an instrumentation site
   is one attribute check — ``TRACER.enabled`` — because
-  :meth:`Tracer.begin` returns 0 and :meth:`Tracer.end` bails on a
-  falsy token.  ``REPRO_TELEMETRY=1`` in the environment (read once at
-  import) or :func:`enable` turns recording on.
+  :meth:`Tracer.begin` returns 0 and every site builds its ``args`` and
+  closes its span under ``if tok:``; :meth:`Tracer.site` hands out one
+  shared no-op context, and the compile listener returns at once.  No
+  clock is read, no annotation made.  ``REPRO_TELEMETRY=1`` in the
+  environment (read once at import) or :func:`enable` turns recording on.
+- **Never a device sync**, on or off: a span's byte counts come from
+  array shapes, and no span waits on or reads from the device.
 - **Enabled**: a span costs two ``perf_counter_ns`` calls, one
   :class:`Span` build, and one append into a lock stripe chosen by
   thread id — concurrent readers/writers on different threads hit
@@ -25,17 +29,40 @@ which is what makes one write traceable end to end: its ``enqueue``
 span carries the ticket ``seq``, its batch's ``commit`` / ``wal_sync``
 / ``publish`` spans carry the commit ``ts`` (range), and the first
 ``read`` span with that ``ts`` is the write becoming visible.
+
+Beyond spans, the tracer keeps three things that tie the ring to the
+device trace, all of them live only while tracing is enabled:
+
+- **Clock anchors** (:meth:`Tracer.anchor`): a ``repro.clock``
+  ``jax.profiler.TraceAnnotation`` entered and left around one
+  ``perf_counter_ns`` reading, recorded in the ring as a ``clock`` span
+  with the same sequence number ``n``.  :mod:`repro.obs.profile` pairs
+  them to move ring spans onto the profiler's clock.
+- **Compile sites** (:meth:`Tracer.site`): a thread-local stack of named
+  regions.  One ``jax.monitoring`` listener, registered at import, turns
+  each backend compile into a ``compile`` span whose ``site`` arg is the
+  innermost open region on the compiling thread (``None`` outside any)
+  and ``sites`` the whole open stack, outermost first.
+- **Window epoch**: :meth:`Tracer.clear` stamps ``epoch_ns``, so
+  ``(span.start_ns - epoch_ns) / 1e9`` is seconds since the clear.
 """
 
 from __future__ import annotations
 
+import contextlib
+import itertools
 import os
 import threading
 import time
 from typing import Dict, List, Optional
 
+import jax.monitoring
+from jax.profiler import TraceAnnotation
+
 _DEFAULT_CAPACITY = 32768
 _N_STRIPES = 8
+CLOCK = "repro.clock"  # the profiler annotation of a clock anchor
+COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
 
 
 class Span:
@@ -122,14 +149,35 @@ def _env_capacity() -> int:
         return _DEFAULT_CAPACITY
 
 
+_NO_SITE = contextlib.nullcontext()  # what Tracer.site hands out while disabled
+
+
+class _Site:
+    __slots__ = ("stack", "name")
+
+    def __init__(self, stack: list, name: str) -> None:
+        self.stack = stack
+        self.name = name
+
+    def __enter__(self) -> None:
+        self.stack.append(self.name)
+
+    def __exit__(self, *exc) -> bool:
+        self.stack.pop()
+        return False
+
+
 class Tracer:
     """Span recorder with per-name counts and an enable switch."""
 
     def __init__(self, capacity: Optional[int] = None) -> None:
         self.enabled = _env_enabled()
         self.ring = SpanRing(capacity if capacity is not None else _env_capacity())
+        self.epoch_ns = 0
         self._counts: Dict[str, int] = {}
         self._count_lock = threading.Lock()
+        self._local = threading.local()
+        self._anchor_seq = itertools.count(1)
 
     # -- hot path ------------------------------------------------------------
     def begin(self) -> int:
@@ -143,19 +191,49 @@ class Tracer:
         """Close a span begun at ``token``.  No-op on a falsy token."""
         if not token or not self.enabled:
             return
-        now = time.perf_counter_ns()
-        self.ring.record(
-            Span(name, cat, token, now - token, threading.get_ident(), ts, args)
-        )
-        with self._count_lock:
-            self._counts[name] = self._counts.get(name, 0) + 1
+        self._record(name, cat, token, time.perf_counter_ns() - token, ts, args)
 
     def instant(self, name: str, cat: str = "store", ts: int = -1,
                 args: Optional[dict] = None) -> None:
         """Record a zero-duration marker span."""
         if not self.enabled:
             return
-        self.end(time.perf_counter_ns(), name, cat=cat, ts=ts, args=args)
+        self._record(name, cat, time.perf_counter_ns(), 0, ts, args)
+
+    def site(self, name: str):
+        """Context naming the code region that any compile inside it is
+        charged to; innermost wins.  Disabled: one shared no-op context."""
+        if not self.enabled:
+            return _NO_SITE
+        stack = getattr(self._local, "sites", None)
+        if stack is None:
+            stack = self._local.sites = []
+        return _Site(stack, name)
+
+    def anchor(self) -> None:
+        """Write one clock anchor into the profiler and the ring (enabled
+        only; the profiler need not be running)."""
+        if not self.enabled:
+            return
+        n = next(self._anchor_seq)
+        with TraceAnnotation(CLOCK, n=n):
+            now = time.perf_counter_ns()
+        self._record("clock", "clock", now, 0, -1, {"n": n})
+
+    def _compiled(self, seconds: float) -> None:
+        """A backend compile of ``seconds`` just ended on this thread."""
+        now = time.perf_counter_ns()
+        dur = int(seconds * 1e9)
+        stack = getattr(self._local, "sites", None) or ()
+        self._record("compile", "compile", now - dur, dur, -1,
+                     {"site": stack[-1] if stack else None, "sites": tuple(stack)})
+
+    def _record(self, name, cat, start_ns, dur_ns, ts, args) -> None:
+        self.ring.record(
+            Span(name, cat, start_ns, dur_ns, threading.get_ident(), ts, args)
+        )
+        with self._count_lock:
+            self._counts[name] = self._counts.get(name, 0) + 1
 
     # -- introspection -------------------------------------------------------
     def count(self, name: str) -> int:
@@ -171,9 +249,12 @@ class Tracer:
         return self.ring.spans()
 
     def clear(self) -> None:
+        """Drop every span and count, and start the window clock:
+        ``epoch_ns`` is now."""
         self.ring.clear()
         with self._count_lock:
             self._counts.clear()
+        self.epoch_ns = time.perf_counter_ns()
 
 
 # Process-wide tracer: the store, pipeline, WAL, compactor, assembler,
@@ -190,4 +271,15 @@ def enable(on: bool = True) -> None:
     TRACER.enabled = bool(on)
 
 
-__all__ = ["Span", "SpanRing", "Tracer", "TRACER", "enable", "enabled"]
+def _on_duration(event: str, seconds: float, **_) -> None:
+    if not TRACER.enabled:
+        return
+    if event == COMPILE_EVENT:
+        TRACER._compiled(seconds)
+
+
+# JAX cannot remove a listener: this one is registered once, at import.
+jax.monitoring.register_event_duration_secs_listener(_on_duration)
+
+
+__all__ = ["CLOCK", "Span", "SpanRing", "Tracer", "TRACER", "enable", "enabled"]
