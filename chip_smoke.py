@@ -290,6 +290,23 @@ def run_ingest(store, base_keys, seed, n_batches, batch):
     return expected, ins, dels
 
 
+def check_capacity_coo(cols, src, dst) -> None:
+    """The device COO in capacity form: ``coo_capacity(E)`` entries per
+    column, the host COO as their prefix, SENTINEL after it."""
+    from repro.core.leaf_pool import SENTINEL
+    from repro.core.view_assembler import coo_capacity
+
+    m = len(src)
+    for name, col, want in (("src", cols[0], src), ("dst", cols[1], dst)):
+        got = np.asarray(col)
+        check(got.shape == (coo_capacity(m),),
+              f"device COO {name}: {got.shape[0]} entries for {m} edges, "
+              f"not the capacity {coo_capacity(m)}")
+        check(np.array_equal(got[:m], want) and bool(np.all(got[m:] == SENTINEL)),
+              f"device COO {name}: the prefix is not the host COO or the tail "
+              "is not SENTINEL")
+
+
 def pin_and_check(store, expected):
     """Pin a view, read its edges back, assemble its device arrays."""
     with Clock() as c_pin:
@@ -302,11 +319,12 @@ def pin_and_check(store, expected):
     report("pin", seconds=f"{c_pin.seconds:.6f}", ts=view.ts,
            edges=view.n_edges, readback="ok")
     with Clock() as c_up:
-        ready(view.to_coo_device())
+        cols = ready(view.to_coo_device())
         ready(view.to_leaf_blocks_device().rows)
     report("assemble", seconds=f"{c_up.seconds:.3f}", kind="set-up",
            tiles=int(view.to_leaf_blocks_device().rows.shape[0]),
-           device_bytes=device_bytes())
+           coo_capacity=int(cols[0].shape[0]), device_bytes=device_bytes())
+    check_capacity_coo(cols, src, dst)
     return handle
 
 

@@ -2,7 +2,12 @@
 
 PR / BFS / SSSP / WCC run as jitted JAX programs over COO edge arrays
 materialized from a :class:`~repro.core.snapshot.SnapshotView` — compiled
-code contains zero version logic (the paper's decoupling).  TC implements the
+code contains zero version logic (the paper's decoupling).  Each program
+drops edges whose ids lie outside ``[0, n)``: its gathers fill them with a
+value neutral to the reduction, and its segment reductions discard them.
+So a capacity-form device COO (``SnapshotView.to_coo_device``), whose tail
+is SENTINEL, gives the same result as the exact COO, bitwise, with shapes
+that hold across commits.  TC implements the
 paper's hybrid set-intersection rule (merge when |N(v)|/|N(u)| < 10, probe
 otherwise, §6.5) on the host, with a device path through the Pallas
 ``intersect`` kernel for leaf-block views.
@@ -49,13 +54,15 @@ def pagerank_coo(
     # damping is static so the update constants are *folded* exactly as in
     # the shard-plane kernel (where damping is a closure constant) — a
     # traced scalar here would round the shared _pr_step expression
-    # differently and break the cross-program bitwise contract
+    # differently and break the cross-program bitwise contract.  An edge
+    # out of [0, n) (a capacity tail) adds no degree, gathers 0 and is
+    # dropped by the contribution's segment_sum.
     deg = jax.ops.segment_sum(jnp.ones_like(src, jnp.float32), src, num_segments=n)
     inv_deg = jnp.where(deg > 0, 1.0 / jnp.maximum(deg, 1.0), 0.0)
     p = jnp.full((n,), 1.0 / n, jnp.float32)
 
     def body(p, _):
-        contrib = (p * inv_deg)[src]
+        contrib = (p * inv_deg).at[src].get(mode="fill", fill_value=0.0)
         agg = jax.ops.segment_sum(contrib, dst, num_segments=n)
         dangling = jnp.sum(jnp.where(deg == 0, p, 0.0))
         return _pr_step(agg, dangling, n, damping), None
@@ -77,9 +84,8 @@ def bfs_coo(src: jnp.ndarray, dst: jnp.ndarray, n: int, root: jnp.ndarray) -> jn
 
     def body(state):
         level, frontier, d = state
-        hit = jax.ops.segment_max(
-            frontier[src].astype(jnp.int32), dst, num_segments=n
-        )
+        reach = frontier.at[src].get(mode="fill", fill_value=False)
+        hit = jax.ops.segment_max(reach.astype(jnp.int32), dst, num_segments=n)
         new = (hit > 0) & (level < 0)
         level = jnp.where(new, d + 1, level)
         return level, new, d + 1
@@ -105,7 +111,8 @@ def sssp_coo(
 
     def body(state):
         dist, _, it = state
-        cand = jax.ops.segment_min(dist[src] + w, dst, num_segments=n)
+        via = dist.at[src].get(mode="fill", fill_value=float("inf")) + w
+        cand = jax.ops.segment_min(via, dst, num_segments=n)
         new = jnp.minimum(dist, cand)
         return new, jnp.any(new < dist), it + 1
 
@@ -126,7 +133,9 @@ def wcc_coo(src: jnp.ndarray, dst: jnp.ndarray, n: int) -> jnp.ndarray:
 
     def body(state):
         labels, _ = state
-        cand = jax.ops.segment_min(labels[src], dst, num_segments=n)
+        cand = jax.ops.segment_min(
+            labels.at[src].get(mode="fill", fill_value=n), dst, num_segments=n
+        )
         new = jnp.minimum(labels, cand)
         # pointer-jump (path halving) accelerates convergence
         new = new[new]
@@ -142,7 +151,8 @@ def wcc_coo(src: jnp.ndarray, dst: jnp.ndarray, n: int) -> jnp.ndarray:
 # small write, reuse the cached per-subgraph arrays instead of rebuilding).
 # By default they take the *device* COO (`view.to_coo_device()`): the edge
 # arrays stay resident on the accelerator, so a warm repeat performs zero
-# host->device transfers.  Pass ``device=False`` (or set
+# host->device transfers, and its capacity form lets a fresh view reuse the
+# programs compiled for an older one.  Pass ``device=False`` (or set
 # ``REPRO_DISABLE_DEVICE_CACHE``) for the host-array path.
 #
 # When the view's store has a shard plane attached
@@ -187,13 +197,17 @@ def sssp_view(view, w: np.ndarray, root: int, device: Optional[bool] = None) -> 
     if plane is not None:
         return plane.sssp(view, w, root)
     src, dst = _view_coo(view, device)
-    return sssp_coo(src, dst, jnp.asarray(w, jnp.float32), view.n_vertices, root)
+    w = np.asarray(w, np.float32)
+    # a capacity-form COO: weights for its tail, whose edges are dropped
+    w = np.pad(w, (0, int(src.shape[0]) - view.n_edges))
+    return sssp_coo(src, dst, jnp.asarray(w), view.n_vertices, root)
 
 
 def wcc_view(view, device: Optional[bool] = None) -> jnp.ndarray:
     """WCC over a directed view: symmetrizes the cached COO (on device when
-    the device cache is active — the concat never round-trips to host; under
-    a shard plane each shard symmetrizes its local edges in-kernel)."""
+    the device cache is active — the concat never round-trips to host and
+    keeps the capacity form's stable shapes; under a shard plane each shard
+    symmetrizes its local edges in-kernel)."""
     plane = _plane(view, device)
     if plane is not None:
         return plane.wcc(view)

@@ -21,12 +21,12 @@ immutability:
    arrays (``jax.lax.dynamic_update_slice`` when segment sizes are
    unchanged, an O(dirty)-run concat otherwise), so post-write assembly is
    O(dirty) device work instead of the O(S) re-concatenation this module's
-   :func:`assemble_leaf_blocks`/:func:`assemble_coo` perform.  A view with
+   :func:`assemble_leaf_blocks` performs.  A view with
    no usable predecessor uploads the host's concatenated arrays once and
    re-pads them with one program, so per-subgraph tiles exist only for
-   snapshots a splice brought in.  Those
-   ``assemble_*`` functions remain as the non-delta full-concat reference
-   used by benchmarks to quantify the splice win.  The assembler's dirty
+   snapshots a splice brought in.  :func:`assemble_leaf_blocks` remains
+   as the non-delta full-concat reference used by benchmarks to quantify
+   the splice win.  The assembler's dirty
    uploads go through :func:`leaf_block_tiles` / :func:`coo_tiles` with
    ``wait=False`` — async prefetch: per-subgraph ``device_put`` is issued
    as soon as each host tile is ready, overlapping transfer with the host
@@ -620,7 +620,9 @@ class DeviceCSRView:
     """Device twin of :class:`~repro.core.snapshot.CSRView`."""
 
     offsets: object  # jax.Array [n_vertices + 1]
-    indices: object  # jax.Array int32 [n_edges]
+    # jax.Array int32; a view's is its capacity-form COO dst column, whose
+    # entries past offsets[-1] are SENTINEL
+    indices: object
 
 
 def assemble_leaf_blocks(snaps: Sequence, B: int) -> DeviceLeafBlockView:
@@ -643,40 +645,12 @@ def assemble_leaf_blocks(snaps: Sequence, B: int) -> DeviceLeafBlockView:
     )
 
 
-def assemble_coo(snaps: Sequence) -> tuple:
-    """Concatenate per-snapshot device COO tiles into global (src, dst)."""
-    import jax.numpy as jnp
-
-    parts = [coo_tiles(s) for s in snaps]
-    if not parts:
-        z = np.zeros(0, np.int32)
-        return _device_put((z, z))
-    return (
-        jnp.concatenate([p[0] for p in parts]),
-        jnp.concatenate([p[1] for p in parts]),
-    )
-
-
-def assemble_csr(snaps: Sequence, n_vertices: int) -> DeviceCSRView:
-    """Device CSR from the cached device COO (offsets computed on device)."""
-    import jax.numpy as jnp
-
-    src, dst = assemble_coo(snaps)
-    degs = jnp.bincount(src, length=n_vertices)
-    offsets = jnp.concatenate([jnp.zeros(1, degs.dtype), jnp.cumsum(degs)])
-    # per-subgraph COO is (u sorted, v sorted) and subgraphs are id-ordered,
-    # so the concatenated dst stream is already in CSR order (as on host).
-    return DeviceCSRView(offsets, dst)
-
-
 __all__ = [
     "CacheStats",
     "DeviceCSRView",
     "DeviceLeafBlockView",
     "DeviceTieredBlocks",
     "split_stream_by_tier",
-    "assemble_coo",
-    "assemble_csr",
     "assemble_leaf_blocks",
     "coo_tiles",
     "enabled",

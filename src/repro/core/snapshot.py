@@ -392,12 +392,22 @@ class SnapshotView:
 
     # -- device materialization ---------------------------------------------------
     def to_coo_device(self):
-        """Global (src, dst) as device-resident ``jax.Array``s.
+        """Global (src, dst) as device-resident ``jax.Array``s, in
+        capacity form.
+
+        Both columns have length ``view_assembler.coo_capacity(n_edges)``:
+        the first ``n_edges`` entries are the edges in (u, v) order, as
+        :meth:`to_coo` has them, and the rest are ``SENTINEL``, out of every
+        vertex range.  The COO programs of :mod:`repro.core.analytics` drop
+        those entries, and views whose edge counts share a capacity give
+        them the same shapes, so a commit does not recompile them.  The
+        host COO (:meth:`to_coo`) stays exact.
 
         Delta-plane assembled: the predecessor view's concatenated device
         arrays are reused and only dirty subgraphs' tiles are spliced in
-        (async-prefetched uploads); a predecessor-less view pays one O(S)
-        device concat.  A warm repeat moves zero bytes host->device.
+        (async-prefetched uploads); a predecessor-less view pays one
+        upload of the host COO.  A warm repeat moves zero bytes
+        host->device.
         """
         from . import view_assembler
 

@@ -60,6 +60,7 @@ from __future__ import annotations
 
 import functools
 import os
+import threading
 from dataclasses import dataclass
 from typing import Dict, List, Optional, Sequence, Tuple
 
@@ -79,6 +80,11 @@ class AssemblyStats:
     calls made during view assembly; a spliced assembly touches exactly the
     dirty subgraphs, a full concat touches all S.  ``reuses`` counts
     assemblies satisfied entirely from the predecessor (empty dirty set).
+    ``coo_capacity_grows`` counts device COOs assembled with another
+    capacity (:func:`coo_capacity`) than the one assembled before them in
+    the process, the scope of the compile cache: each is a new shape for
+    every COO program, so one compile each where the process has not seen
+    it.
 
     Backed by :mod:`repro.obs.metrics` counters (``assembler_<field>`` on
     the process registry) so the values appear in Prometheus exports and
@@ -99,11 +105,14 @@ class AssemblyStats:
         "fallback_no_pred",
         "fallback_lineage",
         "fallback_dirty_frac",
+        "coo_capacity_grows",
     )
 
     def __init__(self, registry: Optional[_metrics.MetricsRegistry] = None) -> None:
         reg = registry if registry is not None else _metrics.REGISTRY
         self._c = {f: reg.counter("assembler_" + f) for f in self._FIELDS}
+        self._cap_lock = threading.Lock()
+        self._last_cap: Optional[int] = None
 
     def __getattr__(self, name: str) -> int:
         c = self.__dict__["_c"].get(name)
@@ -114,9 +123,19 @@ class AssemblyStats:
     def add(self, name: str, delta: int = 1) -> None:
         self._c[name].add(delta)
 
+    def note_coo_capacity(self, cap: int) -> None:
+        """Record a device COO's capacity; count it if it differs from the
+        last one recorded."""
+        with self._cap_lock:
+            last, self._last_cap = self._last_cap, cap
+        if last is not None and last != cap:
+            self.add("coo_capacity_grows")
+
     def reset(self) -> None:
         for c in self._c.values():
             c.reset()
+        with self._cap_lock:
+            self._last_cap = None
 
     def __repr__(self) -> str:  # pragma: no cover - debug aid
         body = ", ".join(f"{f}={self._c[f].value}" for f in self._FIELDS)
@@ -169,6 +188,27 @@ def max_dirty_frac() -> float:
     the numbers backing the default).
     """
     return float(os.environ.get("REPRO_SPLICE_MAX_DIRTY_FRAC", "0.25"))
+
+
+COO_MIN_CAPACITY = 1024
+
+
+def coo_capacity(m: int) -> int:
+    """Length of the device COO columns of a view with ``m`` edges.
+
+    The smallest rung at or above ``m`` of an eighth-octave ladder,
+    ``(8 + j) * 2**(e - 3)`` for ``j`` in 0..7, and at least
+    :data:`COO_MIN_CAPACITY`.  Above the floor a rung wastes under 12.5%
+    of ``m``.  Views whose edge counts share a rung give every COO program
+    the same shapes, so a program compiled for one serves the others: a
+    commit recompiles analytics only when it moves the edge count across
+    a rung.
+    """
+    m = int(m)
+    if m <= COO_MIN_CAPACITY:
+        return COO_MIN_CAPACITY
+    step = 1 << (m.bit_length() - 4)  # 2**(e - 3), 2**e <= m < 2**(e + 1)
+    return -(-m // step) * step
 
 
 # ---------------------------------------------------------------------------
@@ -349,18 +389,18 @@ def _spliced_counts(
     return counts
 
 
-def _splice_runs(pred_cols, pred_offsets, segs, S, concat):
-    """Assemble output columns from clean runs of ``pred_cols`` + dirty segs.
+def _run_parts(pred_cols, pred_offsets, segs, S) -> List[list]:
+    """Per output column, its parts in order: clean runs of ``pred_cols``
+    and the dirty segments.
 
     ``pred_cols`` share the segmentation ``pred_offsets``; ``segs`` maps
     dirty sid -> per-column fresh segment.  Consecutive clean subgraphs
-    collapse into a single slice of the predecessor array, so the part list
-    has at most ``2*len(segs) + 1`` entries — the O(d) splice.
+    collapse into a single slice of the predecessor array, so each part
+    list has at most ``2*len(segs) + 1`` entries — the O(d) splice.
     """
-    dirty = sorted(segs)
     parts: List[list] = [[] for _ in pred_cols]
     cursor = 0
-    for sid in dirty + [S]:
+    for sid in sorted(segs) + [S]:
         if cursor < sid:  # clean run [cursor, sid)
             lo, hi = int(pred_offsets[cursor]), int(pred_offsets[sid])
             if hi > lo:
@@ -373,14 +413,20 @@ def _splice_runs(pred_cols, pred_offsets, segs, S, concat):
             for i in range(len(pred_cols)):
                 parts[i].append(seg[i])
         cursor = sid + 1
+    return parts
+
+
+def _splice_runs(pred_cols, pred_offsets, segs, S, concat):
+    """Assemble output columns from clean runs of ``pred_cols`` + dirty
+    segs (:func:`_run_parts`), joined by ``concat``."""
     out = []
-    for i, col in enumerate(pred_cols):
-        if not parts[i]:
+    for col, parts in zip(pred_cols, _run_parts(pred_cols, pred_offsets, segs, S)):
+        if not parts:
             chosen = col[:0]
-        elif len(parts[i]) == 1:
-            chosen = parts[i][0]
+        elif len(parts) == 1:
+            chosen = parts[0]
         else:
-            chosen = concat(parts[i])
+            chosen = concat(parts)
         if isinstance(chosen, np.ndarray) and chosen.base is not None:
             # a single-run result would otherwise be a VIEW of the
             # predecessor's column: the retained bundle would silently pin
@@ -753,13 +799,35 @@ def _device_segs(view, dirty, tiles_fn) -> Dict[int, tuple]:
     return segs
 
 
-def _splice_device(pred_cols, pred_offsets, segs, S):
+def _concat_padded_impl(parts, cap: int):
+    import jax.numpy as jnp
+
+    from .leaf_pool import SENTINEL
+
+    m = sum(int(p.shape[0]) for p in parts)
+    tail = jnp.full((cap - m,), SENTINEL, parts[0].dtype)
+    return jnp.concatenate(list(parts) + [tail])
+
+
+@functools.cache
+def _concat_padded_program():
+    """The jitted concatenate of a capacity-form column: the parts and the
+    SENTINEL tail in one program per shape set."""
+    import jax
+
+    return jax.jit(_concat_padded_impl, static_argnames=("cap",))
+
+
+def _splice_device(pred_cols, pred_offsets, segs, S, cap: Optional[int] = None):
     """Device-side splice of the predecessor's concatenated jax.Arrays.
 
     Equal-sized dirty segments are patched with
     ``jax.lax.dynamic_update_slice`` directly on the predecessor columns;
-    any resize falls back to an O(d)-run ``jnp.concatenate``.  Returns
-    ``(columns, offsets, patched)``, ``patched`` true on the first path.
+    any resize falls back to an O(d)-run ``jnp.concatenate``, which with a
+    ``cap`` ends in a SENTINEL tail up to ``cap`` entries.  Segments address
+    only the prefix ``pred_offsets`` describes, so a patch leaves a tail
+    untouched.  Returns ``(columns, offsets, patched)``, ``patched`` true on
+    the first path.
     """
     import jax
     import jax.numpy as jnp
@@ -779,11 +847,21 @@ def _splice_device(pred_cols, pred_offsets, segs, S):
                 base = jax.lax.dynamic_update_slice(base, seg, start)
             outs.append(base)
         return tuple(outs), _segment_offsets(counts), True
-    out = _splice_runs(pred_cols, pred_offsets, segs, S, jnp.concatenate)
+    if cap is None:
+        out = _splice_runs(pred_cols, pred_offsets, segs, S, jnp.concatenate)
+    else:
+        prog = _concat_padded_program()
+        out = tuple(
+            prog(tuple(parts) or (col[:0],), cap=cap)
+            for col, parts in zip(
+                pred_cols, _run_parts(pred_cols, pred_offsets, segs, S)
+            )
+        )
     return out, _segment_offsets(counts), False
 
 
-def _end_splice(tok, view, path: str, n_dirty: int, cols, segs=None) -> None:
+def _end_splice(tok, view, path: str, n_dirty: int, cols, segs=None,
+                **extra) -> None:
     """Close the ``view_splice`` span of one view's device assembly.
 
     ``path`` is ``patch`` (equal-sized segments updated in place),
@@ -792,7 +870,8 @@ def _end_splice(tok, view, path: str, n_dirty: int, cols, segs=None) -> None:
     the dirty segments written over all columns; ``bytes_written`` is what
     the device writes, from shapes alone: each eager
     ``dynamic_update_slice`` writes a whole new column, the other paths
-    each output column once.
+    each output column once.  ``extra`` adds arguments of the assembly
+    (a COO's ``cap`` and ``n_edges``).
     """
     per_col = [0] * len(cols)
     for seg in (segs or {}).values():
@@ -804,7 +883,7 @@ def _end_splice(tok, view, path: str, n_dirty: int, cols, segs=None) -> None:
         written = sum(int(c.nbytes) for c in cols)
     _trc.end(tok, "view_splice", cat="read", ts=view.ts, args={
         "n_dirty": n_dirty, "n_subgraphs": len(view.snaps), "path": path,
-        "n_patches": sum(per_col), "bytes_written": written,
+        "n_patches": sum(per_col), "bytes_written": written, **extra,
     })
 
 
@@ -910,14 +989,39 @@ def device_blocks(view):
     return a.dev_blocks
 
 
+def _capacity_cols(cols, cap: int) -> tuple:
+    """Host columns of length ``cap``: ``cols`` then SENTINEL, in the
+    dtypes the device would hold them in (so the upload converts nothing)."""
+    import jax
+
+    from .leaf_pool import SENTINEL
+
+    out = []
+    for c in cols:
+        padded = np.empty(cap, jax.dtypes.canonicalize_dtype(c.dtype))
+        padded[: len(c)] = c
+        padded[len(c):] = SENTINEL
+        out.append(padded)
+    return tuple(out)
+
+
 @_traced("device_coo")
 def device_coo(view) -> tuple:
-    """Device-resident global (src, dst) COO (delta-spliced when possible)."""
+    """Device-resident global (src, dst) COO in capacity form
+    (delta-spliced when possible).
+
+    Each column has length ``coo_capacity(view.n_edges)``: the first
+    ``n_edges`` entries are the edges in (u, v) order, the rest SENTINEL,
+    out of every vertex range, which the COO programs drop.  ``coo_offsets``
+    describes the real segments only.
+    """
     from . import device_cache
 
     a = _bundle(view)
     if a.dev_coo is not None:
         return a.dev_coo
+    m = view.n_edges
+    cap = coo_capacity(m)
     plan = _plan(view)
     if plan is not None and plan[0].dev_coo is not None \
             and plan[0].coo_offsets is not None:
@@ -931,30 +1035,35 @@ def device_coo(view) -> tuple:
         with _trc.site("view_splice"):
             segs = _device_segs(view, dirty, device_cache.coo_tiles)
             cols, offsets, patched = _splice_device(
-                pred.dev_coo, pred.coo_offsets, segs, a.S
+                pred.dev_coo, pred.coo_offsets, segs, a.S, cap=cap
             )
         if tok:
             _end_splice(tok, view, "patch" if patched else "concat",
-                        len(dirty), cols, segs)
+                        len(dirty), cols, segs, cap=cap, n_edges=m)
         a.coo_offsets = offsets
-        a.dev_coo = cols
         _count(splices=1, spliced_segments=len(dirty))
-        return a.dev_coo
-    # full build: one upload of the host COO (see device_blocks)
-    tok = _trc.begin()
-    with _trc.site("view_splice"):
-        cols = device_cache._device_put(host_coo(view), wait=False)
-    if tok:
-        _end_splice(tok, view, "full", a.S, cols)
+    else:
+        # full build: one upload of the host COO, padded on the host (see
+        # device_blocks)
+        tok = _trc.begin()
+        with _trc.site("view_splice"):
+            cols = device_cache._device_put(
+                _capacity_cols(host_coo(view), cap), wait=False
+            )
+        if tok:
+            _end_splice(tok, view, "full", a.S, cols, cap=cap, n_edges=m)
+        _count(full_concats=1)
+    stats.note_coo_capacity(cap)
     a.dev_coo = cols
-    _count(full_concats=1)
     return a.dev_coo
 
 
 @_traced("device_csr")
 def device_csr(view):
     """Device CSR over the (spliced) device COO; offsets computed on device,
-    so no per-subgraph work beyond :func:`device_coo`'s."""
+    so no per-subgraph work beyond :func:`device_coo`'s.  ``indices`` is
+    the COO's capacity-form dst column: ``bincount`` drops the SENTINEL
+    tail, so ``offsets[-1]`` is ``n_edges`` and no offset reaches the tail."""
     from . import device_cache
 
     a = _bundle(view)
@@ -973,6 +1082,7 @@ __all__ = [
     "AssemblyStats",
     "ViewAssembly",
     "block_src_index",
+    "coo_capacity",
     "device_blocks",
     "device_coo",
     "device_csr",

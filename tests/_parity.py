@@ -8,6 +8,8 @@ and the property suites:
 - :func:`bits` — float32 -> uint32 view for *bitwise* comparisons;
 - :func:`pack_padded` — padded ``LeafBlockView`` -> compacted-stream tuple,
   the independent oracle for the compacted layout;
+- :func:`assert_capacity_cols` — a capacity-form device COO column
+  against its exact host twin (length, prefix, SENTINEL tail);
 - :func:`assert_view_matches_oracles` — every materialization layout (host
   COO/CSR/padded blocks/compacted stream, device COO/CSR/blocks) asserted
   bitwise against the per-vertex-loop ``*_uncached`` oracles;
@@ -65,6 +67,20 @@ def pack_padded(lb):
     return lb.rows[mask], offsets, lb.length, lb.src
 
 
+def assert_capacity_cols(cols, exact):
+    """Capacity-form device columns against exact host columns: each has
+    length ``coo_capacity(len(exact))``, its prefix equals the host column
+    bitwise, and its tail is all SENTINEL."""
+    from repro.core.leaf_pool import SENTINEL
+    from repro.core.view_assembler import coo_capacity
+
+    for got, want in zip(cols, exact, strict=True):
+        got, m = np.asarray(got), len(want)
+        assert got.shape == (coo_capacity(m),)
+        assert np.array_equal(got[:m], want)
+        assert np.all(got[m:] == SENTINEL)
+
+
 def assert_view_matches_oracles(view):
     """Bitwise parity of every materialization layout vs the uncached
     oracles: host COO/CSR/blocks/stream and device COO/CSR/blocks."""
@@ -93,12 +109,10 @@ def assert_view_matches_oracles(view):
     assert np.array_equal(np.asarray(db.src), ob.src)
     assert np.array_equal(np.asarray(db.rows), ob.rows)
     assert np.array_equal(np.asarray(db.length), ob.length)
-    dsrc, ddst = view.to_coo_device()
-    assert np.array_equal(np.asarray(dsrc), osrc)
-    assert np.array_equal(np.asarray(ddst), odst)
+    assert_capacity_cols(view.to_coo_device(), (osrc, odst))
     dcsr = view.to_csr_device()
     assert np.array_equal(np.asarray(dcsr.offsets), off)
-    assert np.array_equal(np.asarray(dcsr.indices), odst)
+    assert_capacity_cols((dcsr.indices,), (odst,))
 
 
 # ---------------------------------------------------------------------------
